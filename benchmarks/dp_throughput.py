@@ -65,11 +65,12 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from common import emit, interleave_timed, median_by, make_run
 from repro.config import ModelConfig
 from repro.dp.ghost import per_example_state_bytes
-from repro.launch.mesh import make_compat_mesh, make_host_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_train_setup
 from repro.models.registry import build_model
 
@@ -115,9 +116,12 @@ def bench_point(cfg: ModelConfig, batch: int, seq_len: int, fmt: str,
                 reps: int, modes=ALL_MODES, mesh_shape=None,
                 ghost_microbatch: int = 0) -> dict:
     """One (model, batch) sweep point: median-rep step time per mode."""
-    mesh = (make_compat_mesh(mesh_shape, ("data", "model")[:len(mesh_shape)]
-                             if len(mesh_shape) == 2 else ("data",))
-            if mesh_shape else make_host_mesh())
+    if mesh_shape:
+        axes = ("data", "model")[:len(mesh_shape)]
+        mesh = jax.make_mesh(mesh_shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
+    else:
+        mesh = make_host_mesh()
     data = make_batch(cfg, batch, seq_len)
     qflags = jnp.ones((cfg.policy_len(),), jnp.float32)
     steps = {}

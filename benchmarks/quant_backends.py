@@ -9,8 +9,8 @@ ref/pallas so slow machine drift hits both equally.
 
 On CPU the pallas kernels run in *interpret mode* (Pallas emulates the TPU
 grid with XLA ops), so these numbers measure dispatch correctness and
-interpret overhead, not kernel speed — on real TPUs the fused kernels are
-the production path and REPRO_PALLAS_INTERPRET=0 compiles them.  The JSON
+interpret overhead, not kernel speed — on a TPU backend the same code
+compiles the fused kernels, which are the production path there.  The JSON
 keeps both readings honest: ``pallas_over_ref_step_ratio`` > 1 on CPU is
 expected.
 

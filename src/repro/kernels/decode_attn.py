@@ -11,7 +11,8 @@ dispatch (``repro.quant.backend``) for the quantized cache formats
 
 ``decode_attn_call`` one VMEM pass per (slot, kv-head) grid step: load the
                      packed code rows + their scales, decode (int8 cast /
-                     fp4 nibble unpack) in registers, fold the K scales
+                     fp4 low and high nibbles as two planes) in
+                     registers, fold the K scales
                      into the post-QK scores and the V scales into the
                      pre-PV probabilities, mask by the slot's position,
                      softmax, PV — the dequantized cache never exists in
@@ -28,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 from repro.quant.kv_cache import (fp4_decode_unit, fp4_encode, fp4_row_scale,
                                   int8_encode, int8_row_scale)
@@ -75,40 +77,46 @@ def kv_rowquant_2d(x: jax.Array, fmt: str, block_rows: int = 128,
 # --------------------------------------------------------------------------- #
 # fused decode attention over the quantized slot pool
 # --------------------------------------------------------------------------- #
-def _unit_rows(fmt, codes):
-    """Stored code block (S, Dp) -> unscaled f32 value rows (S, hd_pad)."""
+def _value_planes(fmt, codes):
+    """Stored code block (S, Dp) -> unscaled f32 value planes, each (S, Dp).
+
+    int8 has one plane.  luq_fp4 has two: the low nibbles (even head_dim
+    indices) and the high nibbles (odd ones).  Keeping them apart means the
+    kernel never interleaves lanes; the wrapper splits q and joins the
+    context along the same even/odd layout.
+    """
     if fmt == "int8":
-        return codes.astype(jnp.float32)
-    # luq_fp4: nibble-unpack in registers; even head_dim index = low nibble
+        return (codes.astype(jnp.float32),)
     c = codes.astype(jnp.int32)
-    lo = fp4_decode_unit(c & 0xF)
-    hi = fp4_decode_unit((c >> 4) & 0xF)
-    s, dp = codes.shape
-    return jnp.stack([lo, hi], axis=-1).reshape(s, 2 * dp)
+    return fp4_decode_unit(c & 0xF), fp4_decode_unit((c >> 4) & 0xF)
 
 
 def _decode_attn_kernel(fmt, scale, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                         pos_ref, o_ref):
-    q = q_ref[0, 0].astype(jnp.float32)                   # (g, hd)
-    kvals = _unit_rows(fmt, kc_ref[0, 0])                 # (S, hd)
-    vvals = _unit_rows(fmt, vc_ref[0, 0])
-    ks = ks_ref[...].reshape(1, -1)                       # (1, S)
-    vs = vs_ref[...].reshape(1, -1)
+    kplanes = _value_planes(fmt, kc_ref[0, 0])            # P x (S, Dp)
+    vplanes = _value_planes(fmt, vc_ref[0, 0])
+    ks = ks_ref[0, 0]                                     # (1, S)
+    vs = vs_ref[0, 0]
     # QK with the K scales folded into the (g, S) score matrix
-    scores = jax.lax.dot_general(q, kvals, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    scores = sum(
+        jax.lax.dot_general(q_ref[0, 0, p].astype(jnp.float32), kp,
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        for p, kp in enumerate(kplanes))
     scores = scores * (ks * scale)
     valid = (jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-             <= pos_ref[0, 0])
+             <= pos_ref[pl.program_id(0)])
     scores = jnp.where(valid, scores, -1e30)
     m = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - m)
     probs = e / jnp.sum(e, axis=-1, keepdims=True)
     # PV with the V scales folded into the probabilities (probs * vs is
     # (g, S) — far cheaper than scaling the (S, hd) value rows)
-    o_ref[0, 0] = jax.lax.dot_general(probs * vs, vvals,
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+    w = probs * vs
+    for p, vp in enumerate(vplanes):
+        o_ref[0, 0, p] = jax.lax.dot_general(
+            w, vp, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def decode_attn_call(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
@@ -117,29 +125,34 @@ def decode_attn_call(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
     """Fused decode attention over a quantized cache, one grid step per
     (slot, kv-head).
 
-    ``q``: (B, KV, g, hd) f32 (g and hd tile-padded); ``k_codes`` /
-    ``v_codes``: (B, KV, S, Dp) stored rows (int8: Dp = hd; luq_fp4:
-    Dp = hd // 2); ``k_scale``/``v_scale``: (B, KV, S) f32; ``pos``:
-    (B, 1) int32 per-slot positions.  Padded S rows carry zero scales and
-    indices beyond every ``pos``, so they contribute exactly zero.
-    Returns (B, KV, g, hd) f32 context rows.
+    ``q``: (B, KV, P, g, Dp) f32, split into the P value planes of
+    ``_value_planes`` (int8: P = 1; luq_fp4: P = 2, even then odd head_dim
+    indices), g and Dp tile-padded; ``k_codes`` / ``v_codes``:
+    (B, KV, S, Dp) stored rows (int8: Dp = hd; luq_fp4: Dp = hd // 2);
+    ``k_scale``/``v_scale``: (B, KV, 1, S) f32 (a row per (slot,
+    kv-head), so each block's last two dims are the array's own);
+    ``pos``: (B,) int32 per-slot positions, read from SMEM.  Padded S rows
+    carry zero scales and indices beyond every ``pos``, so they contribute
+    exactly zero.  Returns (B, KV, P, g, Dp) f32 context rows
+    in the same plane layout as ``q``.
     """
-    b, kv, g, hd = q.shape
+    b, kv, planes, g, dp = q.shape
     s = k_codes.shape[2]
-    dp = k_codes.shape[3]
     kernel = lambda *refs: _decode_attn_kernel(fmt, scale, *refs)  # noqa: E731
     return pl.pallas_call(
         kernel,
         grid=(b, kv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, planes, g, dp),
+                         lambda i, j: (i, j, 0, 0, 0)),
             pl.BlockSpec((1, 1, s, dp), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, s), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, s, dp), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1, s), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, planes, g, dp),
+                               lambda i, j: (i, j, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, kv, planes, g, dp), jnp.float32),
         interpret=interpret,
     )(q, k_codes, k_scale, v_codes, v_scale, pos)

@@ -55,7 +55,8 @@ def _ghost_norm_kernel(x_ref, ux_ref, g_ref, ug_ref, ax_ref, ag_ref,
 
     @pl.when(j == nj - 1)
     def _():
-        o_ref[0, 0] = jnp.sum(xx_ref[...] * gg_ref[...])
+        # a (1, 1) vector store: Mosaic cannot store a scalar to VMEM
+        o_ref[...] = jnp.sum(xx_ref[...] * gg_ref[...], keepdims=True)
 
 
 def ghost_norm_gram(x: jax.Array, ux: jax.Array, g: jax.Array,

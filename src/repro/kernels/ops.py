@@ -2,13 +2,13 @@
 
 These own the plumbing the raw kernels don't: uniform-bit generation from a
 PRNG key, per-tensor scale computation, padding to tile multiples, and
-interpret-mode selection (CPU container -> interpret=True; on real TPUs set
-``REPRO_PALLAS_INTERPRET=0`` or pass interpret=False).
+interpret-mode selection: kernels are compiled when JAX's default backend
+is a TPU and run in interpret mode everywhere else (tests that want either
+pass ``interpret`` explicitly).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +23,6 @@ from repro.quant import kv_cache as kvc
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 
@@ -212,22 +209,26 @@ def decode_attn_fused(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
     g = hp // n_kv
     s = k_codes.shape[2]
     dp = k_codes.shape[3]
-    if fmt == "luq_fp4":
-        pad_dp = (-dp) % 64          # packed dim -> 128 decoded lanes
-        hd_padded = 2 * (dp + pad_dp)
-    else:
-        pad_dp = (-dp) % 128
-        hd_padded = dp + pad_dp
+    # luq_fp4 keeps its packed dim: q and the context travel as two planes
+    # (even / odd head_dim indices), matching the low / high nibbles
+    planes = 2 if fmt == "luq_fp4" else 1
+    pad_dp = (-dp) % (64 if fmt == "luq_fp4" else 128)
     pg, ps = (-g) % 8, (-s) % 8
     qg = q.reshape(b, n_kv, g, hd).astype(jnp.float32)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pg), (0, hd_padded - hd)))
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pg),
+                      (0, planes * (dp + pad_dp) - hd)))
+    qg = qg.reshape(b, n_kv, g + pg, dp + pad_dp, planes)
+    qg = qg.transpose(0, 1, 4, 2, 3)
     kc = jnp.pad(k_codes, ((0, 0), (0, 0), (0, ps), (0, pad_dp)))
     vc = jnp.pad(v_codes, ((0, 0), (0, 0), (0, ps), (0, pad_dp)))
-    ks = jnp.pad(k_scale.astype(jnp.float32), ((0, 0), (0, 0), (0, ps)))
-    vs = jnp.pad(v_scale.astype(jnp.float32), ((0, 0), (0, 0), (0, ps)))
-    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)).reshape(b, 1)
+    ks = jnp.pad(k_scale.astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, ps)))[:, :, None, :]
+    vs = jnp.pad(v_scale.astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, ps)))[:, :, None, :]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     ctx = decode_attn_call(qg, kc, ks, vc, vs, pos_b, fmt=fmt, scale=scale,
                            interpret=interpret)
+    ctx = ctx.transpose(0, 1, 3, 4, 2).reshape(b, n_kv, g + pg, -1)
     return ctx[:, :, :g, :hd].reshape(b, hp, hd)
 
 

@@ -30,6 +30,10 @@ from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_serve_setup, build_train_setup  # noqa: E402
 from repro.models.registry import build_model  # noqa: E402
 
+# The production meshes model v5e pods: placeholder CPU devices stand in for
+# the chips, so the roofline peaks are named here, not read from a device.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def cell_skip_reason(cfg, shape) -> str:
     if shape.name == "long_500k" and not cfg.sub_quadratic:
@@ -116,7 +120,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     abstract_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     mf = roofline.model_flops(cfg, abstract_params, shape.kind,
                               shape.global_batch, shape.seq_len, n_dev)
-    terms = roofline.derive(ca, hlo, model_flops_per_device=mf,
+    terms = roofline.derive(ca, hlo, TARGET_DEVICE_KIND,
+                            model_flops_per_device=mf,
                             hlo_analysis=analysis)
 
     rec.update({

@@ -3,39 +3,29 @@
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import and then calls this.
-
-``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exist in newer JAX releases; ``make_compat_mesh``
-papers over the difference so every mesh in the repo builds on any
-supported JAX.
+import and then calls this.  Every mesh is built with ``AxisType.Auto``
+axes: the partitioner places arrays with sharding constraints and leaves
+propagation to GSPMD.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-
-def make_compat_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (CPU tests / small runs)."""
+    """(data, model) mesh over every local device; the model axis has
+    ``model_parallel`` devices, which must divide the device count."""
     n = len(jax.devices())
-    mp = model_parallel if n % model_parallel == 0 else 1
-    return make_compat_mesh((n // mp, mp), ("data", "model"))
+    if model_parallel < 1 or n % model_parallel:
+        raise ValueError(f"model_parallel={model_parallel} does not divide "
+                         f"the {n} available devices")
+    return jax.make_mesh((n // model_parallel, model_parallel),
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -12,8 +12,8 @@ factors use ring-algorithm costs: all-reduce moves ~2x the buffer over the
 slowest link, all-gather / reduce-scatter ~1x, all-to-all ~1x,
 collective-permute 1x.
 
-Hardware constants (v5e-class, from the assignment):
-    197 TFLOP/s bf16 per chip | 819 GB/s HBM | ~50 GB/s/link ICI.
+Hardware constants come from ``PEAKS``, one row per ``Device.device_kind``;
+a device kind missing from it is an error, never a default.
 
 MODEL_FLOPS sanity ratio: 6*N*D (train) / 2*N*D (prefill) / 2*N*B (decode),
 with N_active for MoE — the fraction of compiled compute that is "useful"
@@ -25,9 +25,32 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes / s
-LINK_BW = 50e9               # bytes / s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    bf16_flops: float            # FLOP/s
+    hbm_bytes_per_s: float
+    link_bytes_per_s: float      # one ICI link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+# (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bytes_per_s=819e9,
+                             link_bytes_per_s=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The ``PEAKS`` row for ``device_kind`` (``jax.Device.device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8,
@@ -104,12 +127,15 @@ class RooflineTerms:
         return dataclasses.asdict(self)
 
 
-def derive(cost_analysis: dict, hlo_text: str,
+def derive(cost_analysis: dict, hlo_text: str, device_kind: str,
            model_flops_per_device: Optional[float] = None,
            hlo_analysis: Optional[dict] = None) -> RooflineTerms:
-    """Prefer the trip-count-aware analyzer (repro.launch.hlo_analysis);
+    """Roofline terms against the peaks of ``device_kind``.
+
+    Prefer the trip-count-aware analyzer (repro.launch.hlo_analysis);
     XLA's cost_analysis counts while bodies once and is kept only as a
     cross-reference."""
+    peaks = chip_peaks(device_kind)
     if hlo_analysis is None:
         from repro.launch import hlo_analysis as ha
         hlo_analysis = ha.analyze(hlo_text)
@@ -118,9 +144,9 @@ def derive(cost_analysis: dict, hlo_text: str,
     colls = hlo_analysis["collectives"]
     coll_bytes = sum(colls.values())
     wire = float(hlo_analysis["collective_wire_bytes"])
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    coll_s = wire / LINK_BW
+    compute_s = flops / peaks.bf16_flops
+    memory_s = bytes_accessed / peaks.hbm_bytes_per_s
+    coll_s = wire / peaks.link_bytes_per_s
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     ratio = (model_flops_per_device / flops

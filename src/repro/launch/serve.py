@@ -33,6 +33,7 @@ import numpy as np
 from repro.config import (DPConfig, OptimConfig, QuantConfig, RunConfig,
                           ServeConfig)
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 from repro.runtime.faults import FaultPlan
@@ -78,8 +79,10 @@ def run_oneshot(model, params, mesh, run, args) -> None:
     print("generated token ids:\n", gen)
 
 
-def run_continuous(model, params, args) -> None:
+def run_continuous(model, params, args) -> dict:
     """Continuous-batching path: slot-pool engine with FCFS admission.
+
+    Returns the engine's ``{request_id: RequestResult}``.
 
     With ``--fault-seed`` the run goes through the supervisor under a
     seeded ``FaultPlan`` (chaos mode): faults are injected at their
@@ -135,10 +138,14 @@ def run_continuous(model, params, args) -> None:
         r = results[rid]
         tag = "" if r.status == "ok" else f" [{r.status}]"
         print(f"request {rid}{tag}: {r.tokens.tolist()}")
+    return results
 
 
 def main(argv=None):
-    """Parse flags, build the model, and dispatch to the chosen engine."""
+    """Parse flags, build the model, and dispatch to the chosen engine.
+
+    Returns the continuous engine's results (``None`` for oneshot).
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -182,6 +189,7 @@ def main(argv=None):
     ap.add_argument("--fault-log", default=None,
                     help="chaos mode: write the fired-fault JSON log here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -204,8 +212,8 @@ def main(argv=None):
         run = RunConfig(model=cfg, quant=quant,
                         dp=DPConfig(enabled=False), optim=OptimConfig())
         run_oneshot(model, params, mesh, run, args)
-    else:
-        run_continuous(model, params, args)
+        return None
+    return run_continuous(model, params, args)
 
 
 if __name__ == "__main__":

@@ -14,6 +14,7 @@ import argparse
 
 from repro.config import DPConfig, ModelConfig, OptimConfig, QuantConfig, RunConfig
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.synthetic import ImageClassDataset, NLIDataset, TokenDataset
 from repro.runtime.faults import FaultEvent, FaultPlan
 from repro.runtime.preemption import Preempted, PreemptionHandler
@@ -31,6 +32,7 @@ def make_dataset(cfg: ModelConfig, n: int, seq_len: int, seed: int = 0):
 
 
 def main(argv=None):
+    """Parse flags, train, and return the :class:`Trainer`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--smoke", action="store_true",
@@ -92,6 +94,7 @@ def main(argv=None):
                     help="checkpoint-and-exit on SIGTERM (scheduler "
                          "eviction notice) instead of dying mid-step")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -139,12 +142,13 @@ def main(argv=None):
             tr.ckpt.wait()
         print(f"preempted at step {p.step}; checkpoint written — rerun to "
               "resume")
-        return
+        return tr
     if tr.ckpt:
         tr.ckpt.wait()
     final = tr.history[-1]
     print(f"final: loss={final.loss:.4f} eps={final.eps:.3f} "
           f"acc={final.accuracy}")
+    return tr
 
 
 if __name__ == "__main__":

@@ -17,36 +17,9 @@ dequantize.  All other dims keep their existing (model/data) sharding.
 """
 from __future__ import annotations
 
-import functools
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions, replication checking disabled.
-
-    The replication-check kwarg was renamed (``check_rep`` ->
-    ``check_vma``) across releases and the bodies we wrap (vmapped
-    custom-VJP hooks, scans, psums) are outside what older checkers can
-    prove; callers guarantee replicated outputs themselves (psum /
-    tiled all_gather).  Used by the sharded ghost driver
-    (``repro.dp.ghost.sharded_ghost_clipped_grad_sum``).
-    """
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def _quantize_int8(x, key):
@@ -92,7 +65,7 @@ def compressed_psum_pods(partials, mesh: Mesh, seed: jax.Array,
             qsum = jax.lax.psum(q.astype(jnp.int32), "pod")
             return qsum.astype(jnp.float32) * scale
 
-        fn = _shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                        out_specs=out_spec)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                           out_specs=out_spec)
         out.append(fn(leaf))
     return jax.tree_util.tree_unflatten(treedef, out)

@@ -5,8 +5,7 @@ The quantization stack has two execution backends:
 ``"ref"``     the pure-jnp quantizers in ``repro.quant.formats`` (default;
               runs everywhere, the numerical reference),
 ``"pallas"``  the fused Pallas TPU kernels wrapped in ``repro.kernels.ops``
-              (interpret mode on CPU, compiled on real TPUs — see
-              ``REPRO_PALLAS_INTERPRET`` in kernels/ops.py).
+              (compiled on a TPU, interpret mode on any other backend).
 
 Three ops are dispatched:
 

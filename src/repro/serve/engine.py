@@ -67,6 +67,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.config import ServeConfig
 from repro.launch.mesh import make_host_mesh
@@ -206,6 +207,7 @@ class ContinuousEngine:
         rules = pt.merge_rules(pt.DEFAULT_RULES,
                                model.config.sharding_overrides)
         self._resolver = pt.activation_resolver(self.mesh, rules)
+        self._replicated = NamedSharding(self.mesh, PartitionSpec())
         self._base_key = jax.random.PRNGKey(serve.seed)
         self._jit_fns()
         self.reset()
@@ -303,8 +305,9 @@ class ContinuousEngine:
         """
         K = self.serve.max_slots
         self._next_id = 0
-        self.cache = init_slot_cache(self.model, K, self.serve.max_seq,
-                                     kv_fmt=self.serve.kv_fmt)
+        self.cache = jax.device_put(
+            init_slot_cache(self.model, K, self.serve.max_seq,
+                            kv_fmt=self.serve.kv_fmt), self._replicated)
         self.pool = SlotPool(K)
         self.metrics = ServeMetrics()
         self.queue: collections.deque = collections.deque()
@@ -576,9 +579,11 @@ class ContinuousEngine:
                     # decode this tick
                     return
             if self._dirty:
-                self._tokens_dev = jnp.asarray(self._cur_tokens)
-                self._active_dev = jnp.asarray(self._active)
-                self._rids_dev = jnp.asarray(self._rids)
+                # placed like the step's own outputs, which feed back in
+                # below: one decode program, not one per placement
+                self._tokens_dev, self._active_dev, self._rids_dev = (
+                    jax.device_put((self._cur_tokens, self._active,
+                                    self._rids), self._replicated))
                 self._dirty = False
             toks_dev, self.cache = self._step(
                 self.params, self.cache, self._tokens_dev, self._active_dev,

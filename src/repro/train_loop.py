@@ -60,6 +60,7 @@ class EpochStats:
     quantized_layers: int
     accuracy: Optional[float] = None
     wall_s: float = 0.0
+    steps_s: float = 0.0        # the DP steps alone, to a finished device
 
 
 class Trainer:
@@ -103,8 +104,7 @@ class Trainer:
         self.scheduler = DPQuantScheduler(
             n_layers=run.model.policy_len(), dp=run.dp, mode=mode,
             group_size=group_size, seed=run.seed)
-        self.params = self.model.init(jax.random.PRNGKey(run.seed))
-        self.opt_state = self.setup.opt_init_fn(self.params)
+        self._place(self.model.init(jax.random.PRNGKey(run.seed)), None)
         self.step = 0
         self.history: List[EpochStats] = []
         self.ckpt = (CheckpointManager(checkpoint_dir)
@@ -113,9 +113,21 @@ class Trainer:
         # epoch cursor: train(n) runs n epochs starting here; restore sets
         # it past the checkpointed epoch (or *at* it for mid-epoch resume)
         self._next_epoch = 0
+        self._logits_fn = None
         # mid-epoch resume record ({"epoch", "epoch_step", "epoch_losses"})
         # set by restore_latest when the checkpoint was a preemption save
         self._mid_epoch: Optional[dict] = None
+
+    def _place(self, params, opt_state) -> None:
+        """Commit params and optimizer state (fresh from ``opt_init_fn``
+        when ``None``) to the step's input shardings.  The compiled step
+        and epoch programs return them so placed; placing them so from the
+        start keeps the first call from compiling a second program."""
+        param_sh, opt_sh = self.setup.in_shardings[:2]
+        self.params = jax.device_put(params, param_sh)
+        if opt_state is None:
+            opt_state = self.setup.opt_init_fn(self.params)
+        self.opt_state = jax.device_put(opt_state, opt_sh)
 
     # ------------------------------------------------------------------ #
     def _probe_step(self, params, opt_state, batch, seed, flags):
@@ -166,10 +178,13 @@ class Trainer:
         # ---- DP-SGD steps ----
         start = resume["epoch_step"] if resume else 0
         prior = resume["epoch_losses"] if resume else []
+        t_steps = time.time()
         if run.epoch_executor == "scan":
             losses = self._train_steps_scan(flags, epoch, start, prior)
         else:
             losses = self._train_steps_loop(flags, epoch, start, prior)
+        jax.block_until_ready((self.params, self.opt_state))
+        steps_s = time.time() - t_steps
 
         eps, _ = (self.accountant.get_epsilon(run.dp.delta)
                   if run.dp.enabled else (0.0, 0))
@@ -179,7 +194,7 @@ class Trainer:
         stats = EpochStats(epoch=epoch, loss=float(np.mean(losses)),
                            eps=eps, analysis_eps_fraction=frac,
                            quantized_layers=len(policy), accuracy=acc,
-                           wall_s=time.time() - t0)
+                           wall_s=time.time() - t0, steps_s=steps_s)
         self.history.append(stats)
         if self.ckpt is not None:
             self.save(epoch)
@@ -294,19 +309,25 @@ class Trainer:
         return float((preds == np.asarray(batch["label"])).mean())
 
     def _predict(self, batch, flags):
+        if self._logits_fn is None:
+            # one compiled forward; run eagerly it would compile every
+            # conv and flag branch separately
+            self._logits_fn = jax.jit(self._logits)
+        logits = self._logits_fn(self.params, batch, flags)
+        return np.asarray(jnp.argmax(logits, -1))
+
+    def _logits(self, params, batch, flags):
         from repro.models import resnet as rn, densenet as dn, bert as bt
         cfg, quant = self.run.model, self.run.quant
         if cfg.family == "resnet":
-            logits = rn.forward(self.params, batch["image"], flags, cfg, quant)
-        elif cfg.family == "densenet":
-            logits = dn.forward(self.params, batch["image"], flags, cfg, quant)
-        elif cfg.family == "bert":
-            h = bt.forward(self.params, batch["tokens"], flags, cfg, quant)
-            logits = (h[:, 0].astype(jnp.float32) @ self.params["cls_w"]
-                      + self.params["cls_b"])
-        else:
-            raise ValueError(f"no predict for family {cfg.family}")
-        return np.asarray(jnp.argmax(logits, -1))
+            return rn.forward(params, batch["image"], flags, cfg, quant)
+        if cfg.family == "densenet":
+            return dn.forward(params, batch["image"], flags, cfg, quant)
+        if cfg.family == "bert":
+            h = bt.forward(params, batch["tokens"], flags, cfg, quant)
+            return (h[:, 0].astype(jnp.float32) @ params["cls_w"]
+                    + params["cls_b"])
+        raise ValueError(f"no predict for family {cfg.family}")
 
     # ------------------------------------------------------------------ #
     def save(self, epoch: int, *, epoch_step: int = 0,
@@ -343,8 +364,7 @@ class Trainer:
         if res is None:
             return None
         _, tree, aux = res
-        self.params = tree["params"]
-        self.opt_state = tree["opt"]
+        self._place(tree["params"], tree["opt"])
         self.accountant = RDPAccountant.from_state_dict(aux["accountant"])
         self.scheduler.load_state_dict(aux["scheduler"])
         self.sampler.load_state_dict(aux["sampler"])

@@ -3,7 +3,7 @@ import math
 
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.dp.accountant import (DEFAULT_ORDERS, RDPAccountant,
                                  compute_rdp_sgm, rdp_to_eps)

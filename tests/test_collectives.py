@@ -14,9 +14,10 @@ def test_compressed_psum_matches_exact():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.parallel.collectives import compressed_psum_pods
-        from repro.launch.mesh import make_compat_mesh
+        from jax.sharding import AxisType
 
-        mesh = make_compat_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         key = jax.random.PRNGKey(0)
         # per-pod partials: (pods, 64, 32), model-sharded on last dim
         parts = jax.random.normal(key, (2, 64, 32), jnp.float32)
@@ -52,7 +53,7 @@ def test_multidevice_dp_step_parity():
         from repro.configs import get_smoke_config
         from repro.launch.steps import build_train_setup
         from repro.models.registry import build_model
-        from repro.launch.mesh import make_compat_mesh
+        from jax.sharding import AxisType
 
         cfg = get_smoke_config("gemma-7b")
         model = build_model(cfg, QuantConfig(fmt="none"))
@@ -67,7 +68,8 @@ def test_multidevice_dp_step_parity():
         losses = {}
         for shape, names in [((1, 1), ("data", "model")),
                              ((4, 2), ("data", "model"))]:
-            mesh = make_compat_mesh(shape, names)
+            mesh = jax.make_mesh(shape, names,
+                                 axis_types=(AxisType.Auto,) * len(names))
             setup = build_train_setup(model, run, mesh)
             step = jax.jit(setup.step_fn, in_shardings=setup.in_shardings,
                            out_shardings=setup.out_shardings)

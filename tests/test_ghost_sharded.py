@@ -33,7 +33,7 @@ def test_sharded_ghost_step_matches_single_device():
         from repro.dp.ghost import (ghost_clipped_grad_sum,
                                     sharded_ghost_clipped_grad_sum)
         from repro.models.registry import build_model
-        from repro.launch.mesh import make_compat_mesh
+        from jax.sharding import AxisType
 
         cfg = ModelConfig(name="g", family="dense_lm", n_layers=2,
                           d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -54,7 +54,8 @@ def test_sharded_ghost_step_matches_single_device():
 
         rng = jax.random.PRNGKey(42)
         aux = model.ghost_aux(qflags)
-        mesh = make_compat_mesh((8, 1), ("data", "model"))
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         gu, mu = jax.jit(lambda p, b: ghost_clipped_grad_sum(
             loss_one, pel, p, b, clip_norm=0.8, rng=rng,
             hooked_mask=model.ghost_mask(p), aux=aux))(params, batch)
@@ -89,7 +90,7 @@ def test_sharded_ghost_both_executors_match_single_device():
                                   QuantConfig, ModelConfig)
         from repro.launch.steps import build_train_setup, build_epoch_fn
         from repro.models.registry import build_model
-        from repro.launch.mesh import make_compat_mesh
+        from jax.sharding import AxisType
 
         cfg = ModelConfig(name="g", family="dense_lm", n_layers=2,
                           d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -111,7 +112,8 @@ def test_sharded_ghost_both_executors_match_single_device():
 
         results = {}
         for shape in ((1, 1), (8, 1)):
-            mesh = make_compat_mesh(shape, ("data", "model"))
+            mesh = jax.make_mesh(shape, ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             setup = build_train_setup(model, run, mesh)
             opt0 = setup.opt_init_fn(params0)
             # loop executor

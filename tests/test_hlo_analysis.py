@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch.hlo_analysis import analyze
 from repro.launch import roofline
@@ -78,13 +79,18 @@ def test_bytes_scale_with_loop():
 
 
 def test_roofline_terms():
-    terms = roofline.derive({}, "", hlo_analysis={
+    terms = roofline.derive({}, "", "TPU v5 lite", hlo_analysis={
         "flops": 197e12, "bytes": 819e9, "collectives": {"all-reduce": 25e9},
         "collective_bytes": 25e9, "collective_wire_bytes": 50e9,
         "warnings": [], "entry": "main"})
     assert abs(terms.compute_s - 1.0) < 1e-9
     assert abs(terms.memory_s - 1.0) < 1e-9
     assert abs(terms.collective_s - 1.0) < 1e-9
+
+
+def test_roofline_rejects_unlisted_device():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.chip_peaks("cpu")
 
 
 def test_model_flops_moe_active():

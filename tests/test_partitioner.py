@@ -85,3 +85,11 @@ def test_tree_shardings_real_mesh():
 def test_rank_mismatch_raises():
     with pytest.raises(ValueError):
         spec(("batch",), (4, 4))
+
+
+def test_host_mesh_rejects_model_degree_that_does_not_divide():
+    from repro.launch.mesh import make_host_mesh
+    n = len(jax.devices())
+    assert make_host_mesh(n).devices.shape == (1, n)
+    with pytest.raises(ValueError, match="does not divide"):
+        make_host_mesh(n + 1)

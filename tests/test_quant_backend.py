@@ -12,7 +12,7 @@ from repro.quant import backend as qb
 from repro.quant.formats import STOCHASTIC_FORMATS
 from repro.quant.fake_quant import qeinsum
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 # --------------------------------------------------------------------------- #

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.quant.formats import (int4_uniform, luq_fp4, fp8_e4m3, fp8_e5m2,
                                  make_quantizer, LUQ_EXP_LEVELS)
