@@ -149,6 +149,13 @@ class BackendRecord:
 # --------------------------------------------------------------------------- #
 # train
 # --------------------------------------------------------------------------- #
+def epoch_line(h) -> str:
+    """One ``repro.train_loop.EpochStats`` as ``key=value`` words."""
+    return (f"epoch={h.epoch} loss={h.loss!r} eps={h.eps!r} "
+            f"quantized_layers={h.quantized_layers} wall_s={h.wall_s!r} "
+            f"acc={h.accuracy!r}")
+
+
 def train_phase(log: CompileLog) -> None:
     from repro.launch import train
     for name, extra in TRAIN_RUNS.items():
@@ -157,11 +164,7 @@ def train_phase(log: CompileLog) -> None:
             tr = train.main(TRAIN_ARGV + extra)
         hist = tr.history
         for h in hist:
-            per_step = h.steps_s / tr.run.steps_per_epoch
-            print(f"train {name} epoch={h.epoch} loss={h.loss!r} "
-                  f"eps={h.eps!r} quantized_layers={h.quantized_layers} "
-                  f"steps_s={h.steps_s!r} step_s={per_step!r} "
-                  f"acc={h.accuracy!r}")
+            print(f"train {name} {epoch_line(h)}")
         print(f"train {name} {log.since(snap)}")
         check(len(hist) == 2, f"{name}: {len(hist)} epochs ran, not 2")
         check(all(math.isfinite(h.loss) for h in hist),
@@ -409,9 +412,7 @@ def sharded_ghost_phase(log: CompileLog) -> None:
         tr = Trainer(run, make_dataset(cfg, 4096, run.seq_len, run.seed),
                      mode="static", mesh=mesh)
         h = tr.train(1)[-1]
-        print(f"ghost4 mesh={name} loss={h.loss!r} eps={h.eps!r} "
-              f"quantized_layers={h.quantized_layers} steps_s={h.steps_s!r} "
-              f"{log.since(snap)}")
+        print(f"ghost4 mesh={name} {epoch_line(h)} {log.since(snap)}")
         params[name] = jax.tree_util.tree_map(np.asarray, tr.params)
     worst = 0.0
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(params["1x1"]),

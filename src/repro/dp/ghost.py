@@ -87,6 +87,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.tracing import scope
+
 
 # --------------------------------------------------------------------------- #
 # trace-time context: which ghost pass (if any) the model is being traced for
@@ -555,9 +557,11 @@ def _two_pass(loss_fn, per_example_loss_fn, params, batch, *, clip_norm,
     over whatever batch (or local shard) it is handed.  Returns
     ``(grads_f32_tree, losses, norms)``."""
     r = jax.random.fold_in(rng, 0)   # the vmap path's microbatch-0 fold
-    losses, norms = ghost_per_example_norms(
-        loss_fn, params, batch, rng=r, hooked_mask=hooked_mask, aux=aux,
-        microbatch=ghost_microbatch)
+    losses, norms = scope("ghost_norm_pass", lambda p, b, key: (
+        ghost_per_example_norms(loss_fn, p, b, rng=key,
+                                hooked_mask=hooked_mask, aux=aux,
+                                microbatch=ghost_microbatch)))(
+        params, batch, r)
     scale = jnp.minimum(1.0, clip_norm / jnp.maximum(norms, 1e-12))
     scale = jax.lax.stop_gradient(scale)
 
@@ -568,7 +572,7 @@ def _two_pass(loss_fn, per_example_loss_fn, params, batch, *, clip_norm,
             pel = per_example_loss_fn(p, pass2_batch, r)
         return jnp.vdot(scale, pel.astype(jnp.float32))
 
-    grads = jax.grad(weighted_loss)(params)
+    grads = scope("ghost_grad_pass", jax.grad(weighted_loss))(params)
     return grads, losses, norms
 
 

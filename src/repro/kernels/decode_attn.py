@@ -71,6 +71,7 @@ def kv_rowquant_2d(x: jax.Array, fmt: str, block_rows: int = 128,
         out_shape=[jax.ShapeDtypeStruct((r, d), jnp.int8),
                    jax.ShapeDtypeStruct((r, 1), jnp.float32)],
         interpret=interpret,
+        name="kv_quant",
     )(x)
 
 
@@ -155,4 +156,5 @@ def decode_attn_call(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
                                lambda i, j: (i, j, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, planes, g, dp), jnp.float32),
         interpret=interpret,
+        name="decode_attn",
     )(q, k_codes, k_scale, v_codes, v_scale, pos)

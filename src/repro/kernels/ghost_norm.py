@@ -84,5 +84,6 @@ def ghost_norm_gram(x: jax.Array, ux: jax.Array, g: jax.Array,
         scratch_shapes=[pltpu.VMEM((t, t), jnp.float32),
                         pltpu.VMEM((t, t), jnp.float32)],
         interpret=interpret,
+        name="ghost_norm",
     )(x, ux, g, ug, alpha_x, alpha_g)
     return out

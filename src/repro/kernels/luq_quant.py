@@ -65,4 +65,5 @@ def luq_quant_2d(x: jax.Array, u: jax.Array, alpha: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="luq_quant",
     )(x, u, alpha2d)

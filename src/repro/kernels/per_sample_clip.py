@@ -82,5 +82,6 @@ def per_sample_clip(grads: jax.Array, clip_norm: float, block_d: int = 512,
                    jax.ShapeDtypeStruct((b, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((b, 1), jnp.float32)],
         interpret=interpret,
+        name="per_sample_clip",
     )(grads)
     return out[0], norms[:, 0]

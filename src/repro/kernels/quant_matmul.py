@@ -86,4 +86,5 @@ def quant_matmul(a: jax.Array, b: jax.Array, ua: jax.Array, ub: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(a, b, ua, ub, aa, ab)

@@ -27,6 +27,7 @@ from repro.optim import make_optimizer, apply_updates
 from repro.optim.optimizers import AdamState
 from repro.parallel import partitioner as pt
 from repro.parallel.axes import partitioning_context
+from repro.runtime.tracing import scope
 
 
 def _replicated(mesh):
@@ -156,6 +157,16 @@ def build_train_setup(model: Model, run: RunConfig, mesh: Mesh,
         return jax.tree_util.tree_map(jax.lax.with_sharding_constraint,
                                       b, batch_sh)
 
+    def add_noise(grad_sum, rng):
+        return scope("dp_noise", lambda g, key: add_gaussian_noise(
+            g, clip_norm=run.dp.clip_norm,
+            noise_multiplier=run.dp.noise_multiplier,
+            batch_size=B, rng=key))(grad_sum, rng)
+
+    def update(grads, opt_state, params, lr):
+        updates, new_opt = opt.update(grads, opt_state, params, lr)
+        return apply_updates(params, updates), new_opt
+
     def train_step(params, opt_state, batch, seed, qflags, lr):
         with partitioning_context(resolver):
             rng = jax.random.PRNGKey(seed)
@@ -187,10 +198,7 @@ def build_train_setup(model: Model, run: RunConfig, mesh: Mesh,
                         accum_dtype=accum_dtype, aux=aux,
                         ghost_microbatch=run.dp.ghost_microbatch,
                         constrain=ghost_batch_constrain)
-                grads = add_gaussian_noise(
-                    grad_sum, clip_norm=run.dp.clip_norm,
-                    noise_multiplier=run.dp.noise_multiplier,
-                    batch_size=B, rng=noise_rng)
+                grads = add_noise(grad_sum, noise_rng)
             elif run.dp.enabled:
                 grad_sum, metrics = per_example_clipped_grad_sum(
                     loss_one, params, batch,
@@ -201,18 +209,15 @@ def build_train_setup(model: Model, run: RunConfig, mesh: Mesh,
                                           else 0),
                     constrain_partial=partial_constrain,
                     clip_backend=run.dp.clip_backend)
-                grads = add_gaussian_noise(
-                    grad_sum, clip_norm=run.dp.clip_norm,
-                    noise_multiplier=run.dp.noise_multiplier,
-                    batch_size=B, rng=noise_rng)
+                grads = add_noise(grad_sum, noise_rng)
             else:
                 def mean_loss(p):
                     return model.loss_fn(p, batch, loss_rng, qflags)
                 loss, grads = jax.value_and_grad(mean_loss)(params)
                 metrics = {"loss": loss}
 
-            updates, new_opt = opt.update(grads, opt_state, params, lr)
-            new_params = apply_updates(params, updates)
+            new_params, new_opt = scope("opt_update", update)(
+                grads, opt_state, params, lr)
             return new_params, new_opt, metrics
 
     def init_fn(key):

@@ -22,6 +22,7 @@ from repro.config import ModelConfig, QuantConfig
 from repro.models import common as cm
 from repro.models.registry import Model, register_family
 from repro.parallel.axes import logical_constraint as lc
+from repro.runtime.tracing import scope
 
 
 # --------------------------------------------------------------------------- #
@@ -483,18 +484,16 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
     swrite = jax.vmap(
         lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (0, p)))
 
-    def body(carry, xs):
-        if quantized:
-            blk, kc, vc, ksc, vsc = xs
-        else:
-            blk, kc, vc = xs
-            ksc = vsc = None
-        h = cm.rmsnorm(carry, blk["attn_norm"]).astype(cd)
+    def project_qkv(x, blk):
+        h = cm.rmsnorm(x, blk["attn_norm"]).astype(cd)
         q = jnp.einsum("bd,dhk->bhk", h, blk["wq"].astype(cd))
         k = jnp.einsum("bd,dhk->bhk", h, blk["wk"].astype(cd))
         v = jnp.einsum("bd,dhk->bhk", h, blk["wv"].astype(cd))
         q = cm.rope(q[:, None], positions, cfg.rope_theta)[:, 0]
         k = cm.rope(k[:, None], positions, cfg.rope_theta)[:, 0]
+        return q, k, v
+
+    def write_kv(k, v, kc, vc, ksc, vsc):
         if quantized:
             k_codes, k_sc = kvq(k)                       # (B, KV, Dc) codes
             v_codes, v_sc = kvq(v)
@@ -505,16 +504,34 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
         else:
             kc = write(kc, k[:, :, None, :].astype(kc.dtype), pos)
             vc = write(vc, v[:, :, None, :].astype(vc.dtype), pos)
-        ctx = attend(q, kc, vc, ksc, vsc, pos,
-                     n_kv=cfg.n_kv_heads, scale=attn_scale)
-        attn_out = jnp.einsum("bhk,hkd->bd", ctx.astype(cd),
-                              blk["wo"].astype(cd))
-        x2 = carry + attn_out
-        h2 = cm.rmsnorm(x2, blk["mlp_norm"]).astype(cd)
+        return kc, vc, ksc, vsc
+
+    def attend_cache(q, kc, vc, ksc, vsc):
+        return attend(q, kc, vc, ksc, vsc, pos,
+                      n_kv=cfg.n_kv_heads, scale=attn_scale)
+
+    def project_out(ctx, wo):
+        return jnp.einsum("bhk,hkd->bd", ctx.astype(cd), wo.astype(cd))
+
+    def mlp(x, blk):
+        h2 = cm.rmsnorm(x, blk["mlp_norm"]).astype(cd)
         gate = jnp.einsum("bd,df->bf", h2, blk["wi_gate"].astype(cd))
         up = jnp.einsum("bd,df->bf", h2, blk["wi_up"].astype(cd))
         act = _activation(gate, up, cfg.mlp_activation)
-        x2 = x2 + jnp.einsum("bf,fd->bd", act, blk["wo_mlp"].astype(cd))
+        return jnp.einsum("bf,fd->bd", act, blk["wo_mlp"].astype(cd))
+
+    def body(carry, xs):
+        if quantized:
+            blk, kc, vc, ksc, vsc = xs
+        else:
+            blk, kc, vc = xs
+            ksc = vsc = None
+        q, k, v = scope("attn_proj", project_qkv)(carry, blk)
+        kc, vc, ksc, vsc = scope("kv_write", write_kv)(k, v, kc, vc, ksc,
+                                                        vsc)
+        ctx = scope("decode_attn", attend_cache)(q, kc, vc, ksc, vsc)
+        x2 = carry + scope("attn_proj", project_out)(ctx, blk["wo"])
+        x2 = x2 + scope("mlp", mlp)(x2, blk)
         if quantized:
             return x2, (kc, vc, ksc, vsc)
         return x2, (kc, vc)
@@ -586,19 +603,22 @@ def decode_slots(params, cache, tokens, active, cfg: ModelConfig,
     h_last, upd = _decode_trunk(params, cache, tokens, pos, cfg,
                                 quant=quant, kv_fmt=kv_fmt)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"].T
-    if quant is None or quant.fmt == "none":
-        logits = cm.qlogits(h_last, head, quant_cfg=quant,
-                            key=jax.random.PRNGKey(0))   # key unused
-    else:
+
+    def lm_head(h_last, head, pos):
+        if quant is None or quant.fmt == "none":
+            return cm.qlogits(h_last, head, quant_cfg=quant,
+                              key=jax.random.PRNGKey(0))   # key unused
         # per-slot quantized logits: each slot's (1, d) row goes through
         # the dispatcher with its own position-derived key, matching the
         # oneshot decode_step draw for that position bit-for-bit; vmap
         # batches the K rows into one dispatch with identical bits
         keys = jax.vmap(lambda p: jax.random.fold_in(
             jax.random.PRNGKey(17), 2 * p + 1))(pos)
-        logits = jax.vmap(
+        return jax.vmap(
             lambda hrow, k: cm.qlogits(hrow[None], head, quant_cfg=quant,
                                        key=k)[0])(h_last, keys)
+
+    logits = scope("lm_head", lm_head)(h_last, head, pos)
     new_cache = dict(upd, pos=pos + active.astype(jnp.int32))
     return logits, new_cache
 

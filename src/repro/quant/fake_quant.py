@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.quant import backend as qbackend
+from repro.runtime.tracing import scope
 
 
 def _maybe_quant(x, seed: jax.Array, fold: int, fmt: str, flag: jax.Array,
@@ -63,7 +64,7 @@ def _maybe_quant(x, seed: jax.Array, fold: int, fmt: str, flag: jax.Array,
             jax.random.fold_in(jax.random.PRNGKey(0), seed), fold)
         return q(v, key)
 
-    return jax.lax.cond(flag > 0.5, do_q, lambda v: v, x)
+    return jax.lax.cond(flag > 0.5, scope("quantize", do_q), lambda v: v, x)
 
 
 @functools.lru_cache(maxsize=None)

@@ -74,6 +74,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.parallel import partitioner as pt
 from repro.parallel.axes import partitioning_context
 from repro.runtime.faults import DEFAULT_FREEZE_READS, FaultPlan
+from repro.runtime.tracing import install_gc_span, scope
 from repro.serve.metrics import ServeMetrics
 from repro.serve.slots import SlotPool, init_slot_cache
 
@@ -211,6 +212,7 @@ class ContinuousEngine:
         self._base_key = jax.random.PRNGKey(serve.seed)
         self._jit_fns()
         self.reset()
+        install_gc_span()
 
     # ------------------------------------------------------------------ #
     # device functions
@@ -236,7 +238,9 @@ class ContinuousEngine:
             with partitioning_context(resolver):
                 logits, cache = model.decode_slots(params, cache, tokens,
                                                    active, **kv_kw)
-            pos = cache["pos"]
+            return scope("lm_head", sample)(logits, rids, cache["pos"]), cache
+
+        def sample(logits, rids, pos):
             if temperature > 0:
                 keys = jax.vmap(
                     lambda r, p: sampling_key(base_key, r, p))(rids, pos)
@@ -244,7 +248,7 @@ class ContinuousEngine:
                     k, row / temperature))(keys, logits)
             else:
                 toks = jnp.argmax(logits, -1)
-            return toks.astype(jnp.int32), cache
+            return toks.astype(jnp.int32)
 
         def write_fn(cache, pcache, slot):
             # copy every prefill cache array (codes and, when quantized,
@@ -424,7 +428,8 @@ class ContinuousEngine:
                 if next_ready > now:
                     if clock is None:
                         t_sleep = time.perf_counter()
-                        time.sleep(min(next_ready - now, 0.05))
+                        with jax.profiler.TraceAnnotation("serve.idle"):
+                            time.sleep(min(next_ready - now, 0.05))
                         self.metrics.idle_wall += (time.perf_counter()
                                                    - t_sleep)
                     else:
@@ -480,7 +485,8 @@ class ContinuousEngine:
         shrinks it in degraded mode after replica loss.
         """
         while self.pool.n_free and self.pool.n_active < self.slot_cap:
-            req = self._next_eligible(now_fn())
+            now = now_fn()
+            req = self._next_eligible(now)
             if req is None:
                 return
             prefix = self._tokens_by_req[req.request_id]
@@ -495,29 +501,42 @@ class ContinuousEngine:
                     self.metrics.faults_injected += len(due)
                     self._requeue(req, now_fn())
                     continue
-            slot = self.pool.acquire(req.request_id, req.prompt.size,
-                                     req.max_new_tokens - len(prefix))
             bucket = prefill_bucket(total, self.serve.max_seq)
+            with jax.profiler.TraceAnnotation(
+                    "serve.admit", rid=req.request_id, prompt_len=total,
+                    bucket=bucket,
+                    wait_ms=1000.0 * (now - req.arrival_time)):
+                self._admit_one(req, prefix, total, bucket, now_fn)
+
+    def _admit_one(self, req: Request, prefix: List[int], total: int,
+                   bucket: int, now_fn):
+        """Prefill one request into a free slot and record its first
+        token."""
+        slot = self.pool.acquire(req.request_id, req.prompt.size,
+                                 req.max_new_tokens - len(prefix))
+        with jax.profiler.TraceAnnotation("serve.prefill"):
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :req.prompt.size] = req.prompt
             if prefix:
                 padded[0, req.prompt.size:total] = prefix
             logits, pcache = self._prefill(
                 self.params, {"tokens": jnp.asarray(padded)}, total)
+        with jax.profiler.TraceAnnotation("serve.cache_write"):
             self.cache = self._write(self.cache, pcache, slot)
-            # first generated token, drawn at position == total sequence
-            # length so far (== prompt_len on a fresh admission)
+        # first generated token, drawn at position == total sequence
+        # length so far (== prompt_len on a fresh admission)
+        with jax.profiler.TraceAnnotation("serve.first_token"):
             if self.serve.temperature > 0:
                 key = sampling_key(self._base_key, req.request_id, total)
                 tok = int(jax.random.categorical(
                     key, logits[0] / self.serve.temperature))
             else:
                 tok = int(jnp.argmax(logits[0]))
-            now = now_fn()
-            self._live[req.request_id] = req
-            self.metrics.on_admit(req.request_id, now)
-            self.metrics.on_first_token(req.request_id, now)
-            self._record_token(slot, req, tok, now)
+        now = now_fn()
+        self._live[req.request_id] = req
+        self.metrics.on_admit(req.request_id, now)
+        self.metrics.on_first_token(req.request_id, now)
+        self._record_token(slot, req, tok, now)
 
     def _record_token(self, slot: int, req: Request, tok: int, now: float):
         """Append one generated token; retire the slot if finished."""
@@ -556,53 +575,64 @@ class ContinuousEngine:
         self._tick_index += 1
         t_start = time.perf_counter()
         try:
-            if self.faults is not None:
-                for ev in self.faults.take("clock_freeze", tick):
-                    self.metrics.faults_injected += 1
-                    # read the instant *before* opening the window so the
-                    # frozen value is the current time, then hold it for
-                    # the next `duration` reads
-                    self._freeze_val = now_fn()
-                    self._freeze_reads = ev.duration or DEFAULT_FREEZE_READS
-                for ev in self.faults.take("slot_corrupt", tick):
-                    self.metrics.faults_injected += 1
-                    self.metrics.slot_faults += 1
-                    self._corrupt_slot(ev, now_fn)
-                due = self.faults.take("decode_fail", tick)
-                if due:
-                    self.metrics.faults_injected += len(due)
-                    self.metrics.slot_faults += len(due)
-                    self._fail_tick(now_fn)
-                    return
-                if not self.pool.n_active:
-                    # every occupant was a corruption victim; nothing to
-                    # decode this tick
-                    return
-            if self._dirty:
-                # placed like the step's own outputs, which feed back in
-                # below: one decode program, not one per placement
+            with jax.profiler.TraceAnnotation(
+                    "serve.tick", tick=tick, active=self.pool.n_active,
+                    queued=len(self.queue)):
+                self._decode_tick(tick, now_fn)
+        finally:
+            if self.on_tick is not None:
+                self.on_tick(tick, time.perf_counter() - t_start, now_fn())
+
+    def _decode_tick(self, tick: int, now_fn):
+        """The body of ``_tick``: fault hooks, then the fused step."""
+        if self.faults is not None:
+            for ev in self.faults.take("clock_freeze", tick):
+                self.metrics.faults_injected += 1
+                # read the instant *before* opening the window so the
+                # frozen value is the current time, then hold it for
+                # the next `duration` reads
+                self._freeze_val = now_fn()
+                self._freeze_reads = ev.duration or DEFAULT_FREEZE_READS
+            for ev in self.faults.take("slot_corrupt", tick):
+                self.metrics.faults_injected += 1
+                self.metrics.slot_faults += 1
+                self._corrupt_slot(ev, now_fn)
+            due = self.faults.take("decode_fail", tick)
+            if due:
+                self.metrics.faults_injected += len(due)
+                self.metrics.slot_faults += len(due)
+                self._fail_tick(now_fn)
+                return
+            if not self.pool.n_active:
+                # every occupant was a corruption victim; nothing to
+                # decode this tick
+                return
+        if self._dirty:
+            # placed like the step's own outputs, which feed back in
+            # below: one decode program, not one per placement
+            with jax.profiler.TraceAnnotation("serve.upload"):
                 self._tokens_dev, self._active_dev, self._rids_dev = (
                     jax.device_put((self._cur_tokens, self._active,
                                     self._rids), self._replicated))
-                self._dirty = False
+            self._dirty = False
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
             toks_dev, self.cache = self._step(
                 self.params, self.cache, self._tokens_dev, self._active_dev,
                 self._rids_dev)
+        with jax.profiler.TraceAnnotation("serve.wait"):
             toks = np.asarray(toks_dev)
-            self.metrics.decode_ticks += 1
-            now = now_fn()
+        self.metrics.decode_ticks += 1
+        now = now_fn()
+        with jax.profiler.TraceAnnotation("serve.record"):
             for slot in np.nonzero(self._active)[0]:
                 slot = int(slot)
                 rid = self.pool.state(slot).request_id
                 self._record_token(slot, self._live[rid], int(toks[slot]),
                                    now)
-            if not self._dirty:
-                # no retirement this tick: the sampled tokens feed straight
-                # back in without a host->device upload
-                self._tokens_dev = toks_dev
-        finally:
-            if self.on_tick is not None:
-                self.on_tick(tick, time.perf_counter() - t_start, now_fn())
+        if not self._dirty:
+            # no retirement this tick: the sampled tokens feed straight
+            # back in without a host->device upload
+            self._tokens_dev = toks_dev
 
     # ------------------------------------------------------------------ #
     # fault recovery

@@ -49,6 +49,7 @@ from repro.launch.steps import build_epoch_fn, build_train_setup
 from repro.models.registry import Model, build_model
 from repro.optim.schedule import make_schedule
 from repro.runtime.preemption import Preempted, PreemptionHandler
+from repro.runtime.tracing import install_gc_span
 
 
 @dataclasses.dataclass
@@ -60,7 +61,6 @@ class EpochStats:
     quantized_layers: int
     accuracy: Optional[float] = None
     wall_s: float = 0.0
-    steps_s: float = 0.0        # the DP steps alone, to a finished device
 
 
 class Trainer:
@@ -117,6 +117,7 @@ class Trainer:
         # mid-epoch resume record ({"epoch", "epoch_step", "epoch_losses"})
         # set by restore_latest when the checkpoint was a preemption save
         self._mid_epoch: Optional[dict] = None
+        install_gc_span()
 
     def _place(self, params, opt_state) -> None:
         """Commit params and optimizer state (fresh from ``opt_init_fn``
@@ -135,11 +136,12 @@ class Trainer:
         return self.step_fn(params, opt_state, batch, seed, flags,
                             jnp.float32(lr))
 
-    def _sample_batch(self) -> dict:
-        return self.dataset.get(self.sampler.sample())
-
     # ------------------------------------------------------------------ #
     def train_epoch(self, epoch: int) -> EpochStats:
+        with jax.profiler.TraceAnnotation("train.epoch", epoch=epoch):
+            return self._train_epoch(epoch)
+
+    def _train_epoch(self, epoch: int) -> EpochStats:
         t0 = time.time()
         run = self.run
         resume = None
@@ -153,19 +155,23 @@ class Trainer:
         if resume is None:
             # ---- Algorithm 1 (analysis) ----
             if self.mode == "dpquant":
-                nb = min(run.dp.analysis_batch_size, run.global_batch)
-                nb = max(run.dp.microbatch_size, nb)
-                probe_batches = [self.dataset.get(self._probe_rng.randint(
-                    0, self.dataset.n, nb))
-                    for _ in range(run.dp.analysis_reps)]
-                self.scheduler.maybe_analyze(
-                    probe_step=self._probe_step, params=self.params,
-                    opt_state=self.opt_state, batches=probe_batches,
-                    sample_rate=min(1.0, nb / self.dataset.n),
-                    accountant=self.accountant,
-                    epoch=epoch, seed=run.seed * 1000 + epoch)
+                with jax.profiler.TraceAnnotation("train.analysis",
+                                                  epoch=epoch):
+                    nb = min(run.dp.analysis_batch_size, run.global_batch)
+                    nb = max(run.dp.microbatch_size, nb)
+                    probe_batches = [self.dataset.get(
+                        self._probe_rng.randint(0, self.dataset.n, nb))
+                        for _ in range(run.dp.analysis_reps)]
+                    self.scheduler.maybe_analyze(
+                        probe_step=self._probe_step, params=self.params,
+                        opt_state=self.opt_state, batches=probe_batches,
+                        sample_rate=min(1.0, nb / self.dataset.n),
+                        accountant=self.accountant,
+                        epoch=epoch, seed=run.seed * 1000 + epoch)
             # ---- Algorithm 2 (selection) ----
-            policy = self.scheduler.select(epoch)
+            with jax.profiler.TraceAnnotation("train.select") as span:
+                policy = self.scheduler.select(epoch)
+                span.set_metadata(quantized=len(policy))
         else:
             # mid-epoch resume: analysis + selection already ran before the
             # preemption and their RNG draws / accountant charges are in
@@ -178,26 +184,24 @@ class Trainer:
         # ---- DP-SGD steps ----
         start = resume["epoch_step"] if resume else 0
         prior = resume["epoch_losses"] if resume else []
-        t_steps = time.time()
         if run.epoch_executor == "scan":
             losses = self._train_steps_scan(flags, epoch, start, prior)
         else:
             losses = self._train_steps_loop(flags, epoch, start, prior)
-        jax.block_until_ready((self.params, self.opt_state))
-        steps_s = time.time() - t_steps
 
-        eps, _ = (self.accountant.get_epsilon(run.dp.delta)
-                  if run.dp.enabled else (0.0, 0))
-        frac = (self.accountant.analysis_fraction(run.dp.delta)
-                if run.dp.enabled and self.mode == "dpquant" else 0.0)
-        acc = self.evaluate() if self.eval_dataset is not None else None
-        stats = EpochStats(epoch=epoch, loss=float(np.mean(losses)),
-                           eps=eps, analysis_eps_fraction=frac,
-                           quantized_layers=len(policy), accuracy=acc,
-                           wall_s=time.time() - t0, steps_s=steps_s)
-        self.history.append(stats)
-        if self.ckpt is not None:
-            self.save(epoch)
+        with jax.profiler.TraceAnnotation("train.epoch_end"):
+            eps, _ = (self.accountant.get_epsilon(run.dp.delta)
+                      if run.dp.enabled else (0.0, 0))
+            frac = (self.accountant.analysis_fraction(run.dp.delta)
+                    if run.dp.enabled and self.mode == "dpquant" else 0.0)
+            acc = self.evaluate() if self.eval_dataset is not None else None
+            stats = EpochStats(epoch=epoch, loss=float(np.mean(losses)),
+                               eps=eps, analysis_eps_fraction=frac,
+                               quantized_layers=len(policy), accuracy=acc,
+                               wall_s=time.time() - t0)
+            self.history.append(stats)
+            if self.ckpt is not None:
+                self.save(epoch)
         return stats
 
     def _maybe_preempt(self, epoch: int, epoch_step: int,
@@ -228,18 +232,29 @@ class Trainer:
         run = self.run
         losses = list(prior)
         for es in range(start, run.steps_per_epoch):
-            batch = self._sample_batch()
-            lr = self.schedule(self.step)
-            self.params, self.opt_state, metrics = self.step_fn(
-                self.params, self.opt_state, batch,
-                jnp.uint32(self.step + run.seed), flags, jnp.float32(lr))
-            losses.append(float(metrics["loss"]))
-            if run.dp.enabled:
-                self.accountant.step(
-                    noise_multiplier=run.dp.noise_multiplier,
-                    sample_rate=self.sampler.q, steps=1, label="train")
-            self.step += 1
-            self._maybe_preempt(epoch, es + 1, losses)
+            with jax.profiler.TraceAnnotation("train.chunk", step=self.step,
+                                              k=1):
+                with jax.profiler.TraceAnnotation("train.sample"):
+                    idx = self.sampler.sample()
+                with jax.profiler.TraceAnnotation("train.gather"):
+                    batch = self.dataset.get(idx)
+                with jax.profiler.TraceAnnotation("train.feed"):
+                    seed = jnp.uint32(self.step + run.seed)
+                    lr = jnp.float32(self.schedule(self.step))
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    self.params, self.opt_state, metrics = self.step_fn(
+                        self.params, self.opt_state, batch, seed, flags, lr)
+                with jax.profiler.TraceAnnotation("train.wait"):
+                    losses.append(float(metrics["loss"]))
+                if run.dp.enabled:
+                    with jax.profiler.TraceAnnotation("train.account"):
+                        self.accountant.step(
+                            noise_multiplier=run.dp.noise_multiplier,
+                            sample_rate=self.sampler.q, steps=1,
+                            label="train")
+                self.step += 1
+                with jax.profiler.TraceAnnotation("train.poll"):
+                    self._maybe_preempt(epoch, es + 1, losses)
         return losses
 
     def _train_steps_scan(self, flags, epoch: int, start: int = 0,
@@ -257,24 +272,39 @@ class Trainer:
         done = start
         while done < steps:
             k = min(chunk, steps - done)
-            idx = self.sampler.sample_epoch(k)
-            flat = self.dataset.get(idx.reshape(-1))
-            batches = jax.tree_util.tree_map(
-                lambda x: x.reshape((k, -1) + x.shape[1:]), flat)
-            seeds = jnp.asarray(
-                np.arange(self.step, self.step + k) + run.seed, jnp.uint32)
-            lrs = jnp.asarray([self.schedule(self.step + i) for i in range(k)],
-                              jnp.float32)
-            self.params, self.opt_state, metrics = self.epoch_fn(
-                self.params, self.opt_state, batches, seeds, flags, lrs)
-            losses.extend(float(v) for v in np.asarray(metrics["loss"]))
-            self.step += k
-            done += k
-            if run.dp.enabled:
-                self.accountant.step(
-                    noise_multiplier=run.dp.noise_multiplier,
-                    sample_rate=self.sampler.q, steps=k, label="train")
-            self._maybe_preempt(epoch, done, losses)
+            with jax.profiler.TraceAnnotation("train.chunk", step=self.step,
+                                              k=k):
+                with jax.profiler.TraceAnnotation("train.sample"):
+                    idx = self.sampler.sample_epoch(k)
+                with jax.profiler.TraceAnnotation("train.gather"):
+                    flat = self.dataset.get(idx.reshape(-1))
+                    batches = jax.tree_util.tree_map(
+                        lambda x: x.reshape((k, -1) + x.shape[1:]), flat)
+                with jax.profiler.TraceAnnotation("train.feed"):
+                    seeds = jnp.asarray(
+                        np.arange(self.step, self.step + k) + run.seed,
+                        jnp.uint32)
+                    lrs = jnp.asarray([self.schedule(self.step + i)
+                                       for i in range(k)], jnp.float32)
+                # self.epoch_fn is looked up here, at call time: a wrapper
+                # put on the instance runs inside this span
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    self.params, self.opt_state, metrics = self.epoch_fn(
+                        self.params, self.opt_state, batches, seeds, flags,
+                        lrs)
+                with jax.profiler.TraceAnnotation("train.wait"):
+                    chunk_losses = np.asarray(metrics["loss"])
+                losses.extend(float(v) for v in chunk_losses)
+                self.step += k
+                done += k
+                if run.dp.enabled:
+                    with jax.profiler.TraceAnnotation("train.account"):
+                        self.accountant.step(
+                            noise_multiplier=run.dp.noise_multiplier,
+                            sample_rate=self.sampler.q, steps=k,
+                            label="train")
+                with jax.profiler.TraceAnnotation("train.poll"):
+                    self._maybe_preempt(epoch, done, losses)
         return losses
 
     def train(self, epochs: int, *, eps_budget: Optional[float] = None,
@@ -370,7 +400,11 @@ class Trainer:
         self.sampler.load_state_dict(aux["sampler"])
         if "probe_rng" in aux:
             self._probe_rng.set_state(aux["probe_rng"])
-        self.history = [EpochStats(**d) for d in aux.get("history", [])]
+        # older checkpoints carry fields EpochStats no longer has (steps_s)
+        known = {f.name for f in dataclasses.fields(EpochStats)}
+        self.history = [EpochStats(**{k: v for k, v in d.items()
+                                      if k in known})
+                        for d in aux.get("history", [])]
         self.step = aux["step"]
         if aux.get("mid_epoch"):
             # preemption save: re-enter the interrupted epoch, skipping
