@@ -22,6 +22,7 @@ from repro.config import ModelConfig, QuantConfig
 from repro.models import common as cm
 from repro.models.registry import Model, register_family
 from repro.parallel.axes import logical_constraint as lc
+from repro.parallel.axes import storage_layout
 from repro.runtime.tracing import scope
 
 
@@ -460,6 +461,13 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
     logits-head key schedule is the one place the two decode modes
     legitimately differ, so it stays with the callers.
 
+    The stacked cache arrays (``k``, ``v`` and, quantized, ``k_scale``,
+    ``v_scale``; layer axis first) ride the layer scan in its carry, never
+    as ``xs``/``ys``: layer ``l`` writes only its B new rows, at
+    ``(l, b, :, pos_b)``, and attends to its ``(B, KV, S, Dc)`` view of
+    the carry.  The caller donates the cache, so the returned arrays
+    alias it and the step holds one stacked cache, updated in place.
+
     Quantized cache formats write each row through the dispatched
     ``kv_quant`` op (codes + per-(row, head) bf16 scale) and attend
     through the dispatched ``decode_attn`` op, which fuses dequant into
@@ -476,13 +484,8 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
     quantized = kv_fmt != "none"
     kvq, attend = _kv_impls(kv_fmt, quant)
     attn_scale = 1.0 / math.sqrt(cfg.head_dim)
-
-    # per-row cache write: (KV, S, Dc) gets a (KV, 1, Dc) slab at pos_i;
-    # scale rows (KV, S) get a (KV, 1) slab
-    write = jax.vmap(
-        lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (0, p, 0)))
-    swrite = jax.vmap(
-        lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (0, p)))
+    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
+    axes = kv_cache_axes(cfg, kv_fmt)
 
     def project_qkv(x, blk):
         h = cm.rmsnorm(x, blk["attn_norm"]).astype(cd)
@@ -493,18 +496,26 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
         k = cm.rope(k[:, None], positions, cfg.rope_theta)[:, 0]
         return q, k, v
 
-    def write_kv(k, v, kc, vc, ksc, vsc):
+    def write_kv(k, v, lidx, *kv):
+        # one dynamic_update_slice per row and stacked array: row b's
+        # (KV, Dc) codes land at (lidx, b, :, pos_b, :), its (KV,) scales at
+        # (lidx, b, :, pos_b); each updates the carried buffer in place,
+        # in whatever layout it is stored (a scatter would want its own)
         if quantized:
             k_codes, k_sc = kvq(k)                       # (B, KV, Dc) codes
             v_codes, v_sc = kvq(v)
-            kc = write(kc, k_codes[:, :, None, :].astype(kc.dtype), pos)
-            vc = write(vc, v_codes[:, :, None, :].astype(vc.dtype), pos)
-            ksc = swrite(ksc, k_sc[:, :, None].astype(ksc.dtype), pos)
-            vsc = swrite(vsc, v_sc[:, :, None].astype(vsc.dtype), pos)
+            new = (k_codes, v_codes, k_sc, v_sc)
         else:
-            kc = write(kc, k[:, :, None, :].astype(kc.dtype), pos)
-            vc = write(vc, v[:, :, None, :].astype(vc.dtype), pos)
-        return kc, vc, ksc, vsc
+            new = (k, v)
+        out = []
+        for a, u in zip(kv, new):
+            u = u.astype(a.dtype)
+            for b in range(u.shape[0]):
+                start = (lidx, b, 0, pos[b]) + (0,) * (a.ndim - 4)
+                a = jax.lax.dynamic_update_slice(
+                    a, u[b][None, None, :, None], start)
+            out.append(storage_layout(a))
+        return tuple(out)
 
     def attend_cache(q, kc, vc, ksc, vsc):
         return attend(q, kc, vc, ksc, vsc, pos,
@@ -521,30 +532,23 @@ def _decode_trunk(params, cache, token, pos, cfg: ModelConfig,
         return jnp.einsum("bf,fd->bd", act, blk["wo_mlp"].astype(cd))
 
     def body(carry, xs):
-        if quantized:
-            blk, kc, vc, ksc, vsc = xs
-        else:
-            blk, kc, vc = xs
-            ksc = vsc = None
-        q, k, v = scope("attn_proj", project_qkv)(carry, blk)
-        kc, vc, ksc, vsc = scope("kv_write", write_kv)(k, v, kc, vc, ksc,
-                                                        vsc)
-        ctx = scope("decode_attn", attend_cache)(q, kc, vc, ksc, vsc)
-        x2 = carry + scope("attn_proj", project_out)(ctx, blk["wo"])
+        x, kv = carry
+        blk, lidx = xs
+        q, k, v = scope("attn_proj", project_qkv)(x, blk)
+        kv = scope("kv_write", write_kv)(k, v, lidx, *kv)
+        layer = [jax.lax.dynamic_index_in_dim(a, lidx, keepdims=False)
+                 for a in kv]
+        if not quantized:
+            layer += [None, None]
+        ctx = scope("decode_attn", attend_cache)(q, *layer)
+        x2 = x + scope("attn_proj", project_out)(ctx, blk["wo"])
         x2 = x2 + scope("mlp", mlp)(x2, blk)
-        if quantized:
-            return x2, (kc, vc, ksc, vsc)
-        return x2, (kc, vc)
+        return (x2, kv), None
 
-    if quantized:
-        xs = (params["blocks"], cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"])
-        x, (ks, vs, kss, vss) = jax.lax.scan(body, x, xs)
-        upd = {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}
-    else:
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["blocks"], cache["k"], cache["v"]))
-        upd = {"k": ks, "v": vs}
+    kv = tuple(lc(cache[n], *axes[n]) for n in names)
+    (x, kv), _ = jax.lax.scan(
+        body, (x, kv), (params["blocks"], jnp.arange(cfg.n_layers)))
+    upd = {n: lc(a, *axes[n]) for n, a in zip(names, kv)}
     return cm.rmsnorm(x, params["final_norm"]).astype(jnp.float32), upd
 
 

@@ -309,6 +309,9 @@ class ContinuousEngine:
         """
         K = self.serve.max_slots
         self._next_id = 0
+        # free the old slot cache before allocating its replacement: a
+        # reset never holds two on the device
+        self.cache = None
         self.cache = jax.device_put(
             init_slot_cache(self.model, K, self.serve.max_seq,
                             kv_fmt=self.serve.kv_fmt), self._replicated)

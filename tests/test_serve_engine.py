@@ -394,3 +394,149 @@ def test_temperature_sampling_deterministic_across_runs():
         return [out[r].tokens.tolist() for r in rids]
 
     assert one_run() == one_run()
+
+
+# --------------------------------------------------------------------------- #
+# decode trunk: the stacked cache carried through the layer scan
+# --------------------------------------------------------------------------- #
+def xs_ys_trunk(params, cache, token, pos, cfg, quant=None, kv_fmt="none"):
+    """The decode trunk as it was when each layer's cache entered the scan
+    as ``xs`` and left as ``ys``: the reference the carried trunk must
+    match bit for bit."""
+    from repro.models import common as cm
+    from repro.models import transformer as tfm
+    from repro.runtime.tracing import scope
+    cd = jnp.dtype(cfg.compute_dtype)
+    x = jnp.take(params["embed"], token, axis=0).astype(cd)
+    x = x * jnp.asarray(math.sqrt(cfg.d_model), cd)
+    quantized = kv_fmt != "none"
+    kvq, attend = tfm._kv_impls(kv_fmt, quant)
+    write = jax.vmap(
+        lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (0, p, 0)))
+    swrite = jax.vmap(
+        lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (0, p)))
+
+    def project_qkv(x, blk):
+        h = cm.rmsnorm(x, blk["attn_norm"]).astype(cd)
+        q, k, v = (jnp.einsum("bd,dhk->bhk", h, blk[w].astype(cd))
+                   for w in ("wq", "wk", "wv"))
+        q = cm.rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+        k = cm.rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+        return q, k, v
+
+    def write_kv(k, v, kc, vc, ksc, vsc):
+        if quantized:
+            (k, k_sc), (v, v_sc) = kvq(k), kvq(v)
+            ksc = swrite(ksc, k_sc[:, :, None].astype(ksc.dtype), pos)
+            vsc = swrite(vsc, v_sc[:, :, None].astype(vsc.dtype), pos)
+        kc = write(kc, k[:, :, None, :].astype(kc.dtype), pos)
+        vc = write(vc, v[:, :, None, :].astype(vc.dtype), pos)
+        return kc, vc, ksc, vsc
+
+    def attend_cache(q, kc, vc, ksc, vsc):
+        return attend(q, kc, vc, ksc, vsc, pos, n_kv=cfg.n_kv_heads,
+                      scale=1.0 / math.sqrt(cfg.head_dim))
+
+    def project_out(ctx, wo):
+        return jnp.einsum("bhk,hkd->bd", ctx.astype(cd), wo.astype(cd))
+
+    def mlp(x, blk):
+        h2 = cm.rmsnorm(x, blk["mlp_norm"]).astype(cd)
+        gate = jnp.einsum("bd,df->bf", h2, blk["wi_gate"].astype(cd))
+        up = jnp.einsum("bd,df->bf", h2, blk["wi_up"].astype(cd))
+        act = tfm._activation(gate, up, cfg.mlp_activation)
+        return jnp.einsum("bf,fd->bd", act, blk["wo_mlp"].astype(cd))
+
+    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
+
+    def body(carry, xs):
+        blk, layer = xs[0], list(xs[1:]) + [None] * (4 - len(names))
+        q, k, v = scope("attn_proj", project_qkv)(carry, blk)
+        layer = scope("kv_write", write_kv)(k, v, *layer)
+        ctx = scope("decode_attn", attend_cache)(q, *layer)
+        x2 = carry + scope("attn_proj", project_out)(ctx, blk["wo"])
+        return x2 + scope("mlp", mlp)(x2, blk), layer[:len(names)]
+
+    x, ys = jax.lax.scan(body, x, (params["blocks"],)
+                         + tuple(cache[n] for n in names))
+    return (cm.rmsnorm(x, params["final_norm"]).astype(jnp.float32),
+            dict(zip(names, ys)))
+
+
+def filled_cache(model, n, max_seq, kv_fmt, pos):
+    """A cache whose every row holds seeded values, as if prompts had
+    been prefilled, with ``pos`` as given."""
+    kw = {} if kv_fmt == "none" else {"kv_fmt": kv_fmt}
+    spec = model.slot_cache_spec(n, max_seq, **kw)
+    cache = {}
+    for i, name in enumerate(sorted(set(spec) - {"pos"})):
+        sds, key = spec[name], jax.random.PRNGKey(100 + i)
+        if jnp.issubdtype(sds.dtype, jnp.integer):
+            info = jnp.iinfo(sds.dtype)
+            cache[name] = jax.random.randint(key, sds.shape, info.min,
+                                             info.max, jnp.int32
+                                             ).astype(sds.dtype)
+        elif name.endswith("scale"):
+            cache[name] = jax.random.uniform(key, sds.shape, jnp.float32,
+                                             0.01, 0.1).astype(sds.dtype)
+        else:
+            cache[name] = jax.random.normal(key, sds.shape, sds.dtype)
+    cache["pos"] = jnp.asarray(pos, jnp.int32)
+    return cache
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a if a.dtype.kind in "iub" else a.view(f"u{a.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("mode,kv_fmt,backend", [
+    ("slots", "none", "ref"),
+    ("slots", "int8", "ref"),
+    ("slots", "int8", "pallas"),
+    ("slots", "luq_fp4", "ref"),
+    ("slots", "luq_fp4", "pallas"),
+    ("lockstep", "int8", "pallas"),
+])
+def test_carried_cache_trunk_matches_xs_ys_trunk(mode, kv_fmt, backend,
+                                                 monkeypatch):
+    """Carrying the stacked cache through the layer scan and writing only
+    the new rows gives the logits and every cache array of the xs/ys
+    trunk, bit for bit, tick after tick: slots at staggered positions,
+    one at position 0, inactive ones (one parked at max_seq, whose write
+    is clamped onto the last row)."""
+    from repro.configs import get_smoke_config
+    from repro.models import transformer as tfm
+    model = build_model(get_smoke_config("stablelm-3b"),
+                        QuantConfig(fmt="none", backend=backend))
+    params = model.init(jax.random.PRNGKey(0))
+    max_seq, kw = 16, {} if kv_fmt == "none" else {"kv_fmt": kv_fmt}
+    if mode == "slots":
+        cache = filled_cache(model, 5, max_seq, kv_fmt, [0, 5, 11, 3, 16])
+        active = jnp.asarray([True, True, False, True, False])
+
+        def step(p, c, t):
+            return model.decode_slots(p, c, t, active, **kw)
+    else:
+        cache = filled_cache(model, 3, max_seq, kv_fmt, 5)
+
+        def step(p, c, t):
+            return model.decode_step(p, c, t, **kw)
+    tokens = jnp.arange(cache["k"].shape[1], dtype=jnp.int32) + 7
+    # jit caches a trace by function: each side gets a function of its own
+    carried = jax.jit(lambda *a: step(*a)).lower(params, cache,
+                                                 tokens).compile()
+    with monkeypatch.context() as m:
+        m.setattr(tfm, "_decode_trunk", xs_ys_trunk)
+        reference = jax.jit(lambda *a: step(*a)).lower(params, cache,
+                                                       tokens).compile()
+    got = want = cache
+    for _ in range(4):
+        logits, got = carried(params, got, tokens)
+        ref_logits, want = reference(params, want, tokens)
+        np.testing.assert_array_equal(_bits(logits), _bits(ref_logits))
+        assert sorted(got) == sorted(want)
+        for name in want:
+            np.testing.assert_array_equal(_bits(got[name]),
+                                          _bits(want[name]), err_msg=name)
+        tokens = jnp.argmax(ref_logits, -1).astype(jnp.int32)

@@ -113,3 +113,41 @@ def test_decode_attn_compiles(chip, fmt):
              _sds(chip, cache, jnp.bfloat16), _sds(chip, cache, jnp.bfloat16),
              _sds(chip, (SLOTS,), jnp.int32),
              fmt=fmt, n_kv=STABLELM_KV, scale=STABLELM_HD ** -0.5)
+
+
+def test_decode_slots_updates_the_donated_cache_in_place(chip, monkeypatch):
+    """The fused decode tick over a donated int8 slot cache, at stablelm-3b's
+    widths with two layers and 14 slots x 2048, holds no second stacked
+    cache: the layer scan carries the cache and writes only the new rows,
+    so the step's temporaries stay below one stacked cache's bytes."""
+    import dataclasses
+
+    from repro.config import QuantConfig
+    from repro.configs import get_config
+    from repro.models.registry import build_model
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2,
+                              param_dtype="bfloat16")
+    model = build_model(cfg, QuantConfig(fmt="none", backend="pallas"))
+    slots = 14
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = sds(model.slot_cache_spec(slots, MAX_SEQ, kv_fmt="int8"))
+
+    def step(p, c, tokens, active):
+        logits, c = model.decode_slots(p, c, tokens, active, kv_fmt="int8")
+        return jnp.argmax(logits, -1), c
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, _sds(chip, (slots,), jnp.int32),
+        _sds(chip, (slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    stacked = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for name, a in cache.items() if name != "pos")
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= stacked
+    assert ma.temp_size_in_bytes < stacked, (ma.temp_size_in_bytes, stacked)
